@@ -1,8 +1,8 @@
 """Kernel micro-benchmarks + Gen-DST convergence timing (paper §3.3).
 
 Times the XLA reference paths (the production CPU-measurable numbers) and
-validates the Pallas kernels in interpret mode.  On a real TPU the Pallas
-paths are enabled with use_pallas=True, interpret=False.
+validates the Pallas kernels, which run in interpret mode on the CPU backend
+and compile anywhere else (``interpret=None``).
 """
 from __future__ import annotations
 

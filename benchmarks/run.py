@@ -380,4 +380,6 @@ def _run_roofline():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from benchmarks.common import QUICK_AUTOML, run_dataset, substrat_config  # noqa: E402
 from repro.data.tabular import PAPER_DATASETS  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -41,4 +42,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -34,6 +34,7 @@ from repro.data.tabular import PAPER_DATASETS, make_dataset, train_test_split  #
 from repro.service import (  # noqa: E402
     SubStratHTTPClient, SubStratHTTPServer, SubStratServer,
 )
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 # sample line: name{label="v",...} value  — value may be int/float/+Inf
 _SAMPLE_RE = re.compile(
@@ -139,4 +140,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -43,6 +43,7 @@ from repro.core.plan import plan  # noqa: E402
 from repro.data.tabular import PAPER_DATASETS, make_dataset, train_test_split  # noqa: E402
 from repro.obs import jaxprof  # noqa: E402
 from repro.service import SubStratServer  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def run_round(srv, datasets, p, n_jobs, key0):
@@ -137,4 +138,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

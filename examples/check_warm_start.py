@@ -27,6 +27,7 @@ from repro.core.plan import plan  # noqa: E402
 from repro.meta import portfolio_for  # noqa: E402
 from repro.service import SubStratServer, wire  # noqa: E402
 from repro.service.scheduler import Scheduler  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def make_data(seed: int, N: int = 400, d: int = 8):
@@ -118,4 +119,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

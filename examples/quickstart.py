@@ -26,6 +26,7 @@ from repro.core.gen_dst import GenDSTConfig  # noqa: E402
 from repro.core.plan import execute, plan  # noqa: E402
 from repro.core.strategies import available_strategies  # noqa: E402
 from repro.data.tabular import PAPER_DATASETS, make_dataset, train_test_split  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -75,4 +76,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

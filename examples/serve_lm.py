@@ -11,6 +11,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.launch.serve import main as serve_main  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -23,4 +24,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

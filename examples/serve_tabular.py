@@ -49,6 +49,7 @@ from repro.obs.trace import render_timeline  # noqa: E402
 from repro.service import (  # noqa: E402
     BudgetExceeded, DistributedScheduler, ProcessWorkerPool, SubStratServer,
 )
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -189,4 +190,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

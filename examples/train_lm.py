@@ -15,6 +15,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.launch.train import main as train_main  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -37,4 +38,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,7 +20,7 @@ Search-loop architecture (DESIGN.md §5.5):
     reweights the entropy average.  Full recomputes happen only on
     row-crossover generations (``cross_every`` cadence) and route through
     ``kernels/entropy`` under the ``backend=`` switch ("jnp" scatter-add
-    reference, or the Pallas MXU kernel).
+    reference, or the Pallas histogram kernel).
   * Islands: ``num_islands`` independent sub-populations evolved under one
     vmap, with ring elite migration every ``migrate_every`` generations
     (each island's worst ``migrate_frac * phi`` candidates are replaced by
@@ -48,7 +48,7 @@ from .measures import (
     subset_counts,
     MEASURES,
 )
-from ..kernels.entropy.ops import population_histogram, resolve_interpret
+from ..kernels.entropy.ops import population_histogram
 from ..kernels.gen_dst.ops import fused_delta_fitness
 from ..obs.jaxprof import note_trace
 
@@ -69,6 +69,11 @@ def _validate_cfg(cfg: "GenDSTConfig") -> None:
         raise ValueError(
             f"unknown Gen-DST backend {cfg.backend!r}; expected one of "
             f"{', '.join(GEN_DST_BACKENDS)}")
+    if cfg.measure != "entropy" and cfg.backend != "jnp":
+        # the Pallas kernels compute entropy histograms only
+        raise ValueError(
+            f"Gen-DST backend {cfg.backend!r} needs measure='entropy'; "
+            f"measure {cfg.measure!r} runs only on backend='jnp'")
 
 
 class GenDSTConfig(NamedTuple):
@@ -80,9 +85,9 @@ class GenDSTConfig(NamedTuple):
     measure: str = "entropy"
     # --- search-loop extensions (DESIGN.md §5.5, §16) ----------------------
     # execution backend: "jnp" (XLA reference, the bit-level oracle),
-    # "pallas" (MXU histogram on full recomputes only), or "pallas_fused"
+    # "pallas" (Pallas histogram on full recomputes only), or "pallas_fused"
     # (the §16 kernel: delta-update + fitness fused into one VMEM-resident
-    # launch per generation, MXU histogram on crossover recomputes)
+    # launch per generation, Pallas histogram on crossover recomputes)
     backend: str = "jnp"
     incremental: bool = True   # delta-update counts on mutation-only gens
     cross_every: int = 1   # crossover every k-th generation (1 = seed-faithful)
@@ -190,13 +195,13 @@ def _generic_fitness(values, measure_fn, f_ref, rows, cols):
     return jax.vmap(one)(rows, cols)
 
 
-def _population_counts(codes, rows, B, *, backend, interpret):
+def _population_counts(codes, rows, B, *, backend):
     """(..., phi, n) row indices -> (..., phi, M, B) per-candidate counts."""
     lead = rows.shape[:-1]
     n = rows.shape[-1]
     M = codes.shape[1]
     sub = jnp.take(codes, rows.reshape(-1, n), axis=0)        # (P, n, M)
-    hist = population_histogram(sub, B, backend=backend, interpret=interpret)
+    hist = population_histogram(sub, B, backend=backend)
     return hist.reshape(*lead, M, B)
 
 
@@ -398,18 +403,14 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
     N, M = codes.shape
     I, phi = cfg.num_islands, cfg.phi
     entropy = cfg.measure == "entropy"
-    interpret = resolve_interpret(None)
-
-    use_fused = entropy and cfg.backend == "pallas_fused"
+    use_fused = cfg.backend == "pallas_fused"
 
     def pop_counts(rows):
         # full-recompute histograms: the fused backend shares the entropy
-        # kernel's MXU one-hot-contraction path (DESIGN.md §16.3)
+        # kernel's histogram path (DESIGN.md §16.3)
         hist_backend = "pallas" if cfg.backend in ("pallas", "pallas_fused") \
             else "jnp"
-        return _population_counts(
-            codes, rows, B, backend=hist_backend, interpret=interpret
-        )
+        return _population_counts(codes, rows, B, backend=hist_backend)
 
     if entropy:
         h_full = full_column_entropy(codes, B)
@@ -458,7 +459,7 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
         if use_fused:
             # §16 path: the cond only decides *which counts and delta* feed
             # the fused kernel; delta-update + fitness always run as one
-            # launch.  Crossover generations rebuild histograms on the MXU
+            # launch.  Crossover generations rebuild histograms on the Pallas
             # path and pass a zero delta, so both branches share one
             # fitness code path (and one jaxpr shape for the cond).
             no_delta = jnp.zeros_like(applied)
@@ -484,7 +485,7 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
                 jnp.take(codes, old_vals, axis=0),
                 jnp.take(codes, fresh, axis=0),
                 app, cols2, f_ref,
-                backend="pallas_fused", interpret=interpret,
+                backend="pallas_fused",
             )
         else:
             def with_cross(_):

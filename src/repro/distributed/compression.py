@@ -49,9 +49,7 @@ def compressed_psum(x: jax.Array, axis_name: str):
     """int8-transport all-reduce over ``axis_name`` (call inside shard_map).
 
     x: (N,) f32 with N divisible by the axis size."""
-    # jax.lax.axis_size is only in newer jax; psum(1) is the portable spelling
-    k = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis_name))
+    k = jax.lax.axis_size(axis_name)
     n = x.shape[0]
     chunks = x.reshape(k, n // k)
     q, scale = quantize_int8(chunks)                       # int8 (k, n/k)

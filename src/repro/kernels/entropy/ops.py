@@ -10,10 +10,10 @@ population recompute is a single ``masked_histogram_pallas`` launch.
 Backend selection:
   * ``backend="jnp"``     — XLA scatter-add reference (`ref.py`); the
     production path on CPU and the correctness oracle everywhere.
-  * ``backend="pallas"``  — the MXU one-hot-contraction kernel
-    (`kernel.py`).  On TPU pass ``interpret=False``; CPU tests and the
-    default ``interpret=None`` (auto) run the kernel body in interpret
-    mode, which validates semantics but is slow — never the CPU prod path.
+  * ``backend="pallas"``  — the VMEM one-hot histogram kernel
+    (`kernel.py`).  The default ``interpret=None`` compiles it on the chip
+    and interprets it only on the CPU backend, which validates semantics
+    but is slow — never the CPU prod path.
 """
 from __future__ import annotations
 
@@ -29,15 +29,7 @@ __all__ = [
     "masked_histogram",
     "column_entropy_masked",
     "population_histogram",
-    "resolve_interpret",
 ]
-
-
-def resolve_interpret(interpret=None) -> bool:
-    """Pallas interpret-mode default: compiled on TPU, interpreted elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
 
 
 def masked_histogram(
@@ -46,7 +38,7 @@ def masked_histogram(
     bins: int,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     if use_pallas:
         return masked_histogram_pallas(codes, weights, bins, interpret=interpret)
@@ -69,7 +61,7 @@ def population_histogram(
     bins: int,
     *,
     backend: str = "jnp",
-    interpret: bool | None = None,   # None = auto: compiled on TPU
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Per-candidate histograms: out[p, m, b] = |{i : sub_codes[p, i, m] == b}|.
 
@@ -81,9 +73,7 @@ def population_histogram(
     ones = jnp.ones((n,), jnp.float32)
     if backend == "pallas":
         flat = sub_codes.transpose(1, 0, 2).reshape(n, P * M)
-        hist = masked_histogram_pallas(
-            flat, ones, bins, interpret=resolve_interpret(interpret)
-        )
+        hist = masked_histogram_pallas(flat, ones, bins, interpret=interpret)
         return hist.reshape(P, M, bins)
     if backend != "jnp":
         raise ValueError(f"unknown histogram backend: {backend!r}")

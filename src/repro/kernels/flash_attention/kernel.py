@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -83,7 +85,7 @@ def flash_attention_pallas(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: interpreted only on the CPU
 ) -> jax.Array:
     B, Sq, H, hd = q.shape
     _, Skv, Kh, _ = k.shape
@@ -120,6 +122,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

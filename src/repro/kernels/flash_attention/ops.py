@@ -10,7 +10,7 @@ from .ref import attention_ref
 __all__ = ["flash_attention"]
 
 
-def flash_attention(q, k, v, *, causal=True, use_pallas=False, interpret=True):
+def flash_attention(q, k, v, *, causal=True, use_pallas=False, interpret=None):
     if use_pallas:
         return flash_attention_pallas(q, k, v, causal=causal, interpret=interpret)
     return attention_ref(q, k, v, causal=causal)

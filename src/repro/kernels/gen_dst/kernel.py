@@ -16,7 +16,7 @@ The jnp path reads the (P, M, B) counts from HBM twice per generation
 between; the fused kernel reads it once, writes it once (in-place via
 ``input_output_aliases``), and emits the (P,) fitness from the same
 residency.  Crossover (full-recompute) generations route the histogram
-rebuild through ``kernels/entropy``'s MXU path and then this kernel with
+rebuild through ``kernels/entropy``'s histogram kernel and then this one with
 ``applied = 0`` — a zero delta — so *every* generation's fitness comes
 from one code path.
 
@@ -32,6 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 __all__ = ["fused_delta_fitness_kernel", "fused_delta_fitness_pallas"]
 
@@ -84,7 +86,7 @@ def fused_delta_fitness_pallas(
     *,
     bins: int,
     tile_p: int = 8,
-    interpret: bool = True,   # CPU validation default; False on real TPU
+    interpret: bool | None = None,   # None: interpreted only on the CPU
 ):
     P, M, B = counts.shape
     assert B == bins
@@ -121,7 +123,7 @@ def fused_delta_fitness_pallas(
         # the count tensor is updated in place: one HBM read + one write
         # per generation instead of the jnp path's read/write/read
         input_output_aliases={0: 0},
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(counts_p, oldc_p, newc_p, w_p, cols_p,
       jnp.asarray(f_ref, jnp.float32).reshape(1, 1))
     return counts_out[:P], fit[:P, 0]

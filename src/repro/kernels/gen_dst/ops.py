@@ -11,10 +11,9 @@ Backend selection mirrors ``kernels/entropy/ops.py``:
   * ``backend="jnp"``          — scatter-add + entropy reference
     (`ref.py`); the production CPU path and the bit-level oracle.
   * ``backend="pallas_fused"`` — the VMEM-resident fused kernel
-    (`kernel.py`).  On TPU pass ``interpret=False``; CPU tests and the
-    default ``interpret=None`` (auto) run the kernel body in interpret
-    mode, which validates semantics but is slow — never the CPU prod
-    path.
+    (`kernel.py`).  The default ``interpret=None`` compiles it on the
+    chip and interprets it only on the CPU backend, which validates
+    semantics but is slow — never the CPU prod path.
 
 Leading axes: inputs may carry any leading shape (Gen-DST calls with
 ``(islands, phi, ...)``); everything is flattened to one candidate axis
@@ -28,11 +27,10 @@ import jax
 import jax.numpy as jnp
 
 from ...obs.jaxprof import note_trace
-from ..entropy.ops import resolve_interpret
 from .kernel import fused_delta_fitness_pallas
 from .ref import fused_delta_fitness_ref
 
-__all__ = ["fused_delta_fitness", "resolve_interpret"]
+__all__ = ["fused_delta_fitness"]
 
 
 @functools.partial(
@@ -47,7 +45,7 @@ def fused_delta_fitness(
     f_ref: jax.Array,         # scalar F(D)
     *,
     backend: str = "jnp",
-    interpret: bool | None = None,   # None = auto: compiled on TPU
+    interpret: bool | None = None,
     tile_p: int = 8,
 ):
     """``(counts', fitness)``: one fused Gen-DST generation update.
@@ -67,7 +65,7 @@ def fused_delta_fitness(
     if backend == "pallas_fused":
         c2, fit = fused_delta_fitness_pallas(
             cf, of, nf, af, mf, f_ref, bins=B, tile_p=tile_p,
-            interpret=resolve_interpret(interpret),
+            interpret=interpret,
         )
     elif backend == "jnp":
         c2, fit = fused_delta_fitness_ref(cf, of, nf, af, mf, f_ref)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 __all__ = ["ssd_scan_pallas"]
 
 
@@ -67,7 +69,7 @@ def ssd_scan_pallas(
     cm: jax.Array,    # (BH, S, N)
     *,
     block_q: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: interpreted only on the CPU
 ) -> jax.Array:
     BH, S, P = x.shape
     N = bm.shape[-1]
@@ -87,5 +89,5 @@ def ssd_scan_pallas(
         out_specs=pl.BlockSpec((1, block_q, P), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, a, bm, cm)

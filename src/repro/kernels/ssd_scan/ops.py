@@ -11,7 +11,7 @@ from .ref import ssd_scan_ref
 __all__ = ["ssd_scan"]
 
 
-def ssd_scan(x, dt, a, bm, cm, *, use_pallas=False, interpret=True,
+def ssd_scan(x, dt, a, bm, cm, *, use_pallas=False, interpret=None,
              block_q: int = 128):
     """Model layout: x (B,S,H,P), dt (B,S,H), a (H,), bm/cm (B,S,G,N)."""
     B, S, H, P = x.shape

@@ -38,7 +38,7 @@ from repro.distributed.sharding import (
     tree_shardings,
 )
 from repro.launch.flops import model_flops, active_params
-from repro.launch.hlo_costs import analyze_hlo, xla_cost_dict
+from repro.launch.hlo_costs import analyze_hlo
 from repro.launch.mesh import make_production_mesh
 from repro.models import encdec, lm
 from repro.models.config import SHAPES, ShapeSpec
@@ -254,7 +254,7 @@ def run_cell(arch_id: str, shape: ShapeSpec, multi_pod: bool, verbose=True):
         compiled = lowered.compile()
         t_compile = time.time() - t0
     ma = compiled.memory_analysis()
-    xla_cost = xla_cost_dict(compiled.cost_analysis())
+    xla_cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     hcost = analyze_hlo(hlo)
     mem = {

@@ -23,16 +23,7 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-__all__ = ["analyze_hlo", "HloCost", "xla_cost_dict"]
-
-
-def xla_cost_dict(analysis) -> dict:
-    """``compiled.cost_analysis()`` compat: newer jax returns one dict,
-    jax 0.4.x a per-device list of dicts (the partitioned entries are
-    identical — take the first)."""
-    if isinstance(analysis, (list, tuple)):
-        return analysis[0] if analysis else {}
-    return analysis
+__all__ = ["analyze_hlo", "HloCost"]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

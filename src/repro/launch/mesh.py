@@ -7,19 +7,6 @@ import jax
 __all__ = ["make_production_mesh", "make_mesh"]
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """Explicit-Auto axis types where the jax version supports them.
-
-    jax >= 0.6 exposes ``jax.sharding.AxisType`` and ``make_mesh`` accepts
-    ``axis_types``; older versions (0.4.x) have neither — Auto is already
-    their only behavior, so the kwarg is simply omitted.
-    """
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return {}
-    return {"axis_types": (at.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) (data, model) = 256 chips.
     Multi-pod: (2, 16, 16) (pod, data, model) = 512 chips; ``pod`` is an
@@ -31,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(
         shape, axes,
         devices=jax.devices()[:n],
-        **_axis_types_kw(len(axes)),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 
@@ -39,5 +26,5 @@ def make_mesh(shape, axes):
     """Arbitrary mesh (tests / smoke runs)."""
     return jax.make_mesh(
         tuple(shape), tuple(axes),
-        **_axis_types_kw(len(axes)),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )

@@ -24,8 +24,8 @@ callable that receives ``(name, seconds, meta)`` after every scheduler
 dispatch — the seam for wiring ``jax.profiler`` traces or external
 telemetry to exactly the dispatches of interest without patching the
 scheduler.  ``install_monitoring()`` additionally subscribes to
-``jax.monitoring`` events (best-effort; event names vary by jax version)
-so XLA's own compile events land in the same exposition.
+``jax.monitoring`` events so XLA's own compile events land in the same
+exposition.
 """
 from __future__ import annotations
 
@@ -93,7 +93,7 @@ def reset_tracing() -> None:
 
 
 # ---------------------------------------------------------------------------
-# jax.monitoring bridge (best-effort)
+# jax.monitoring bridge
 # ---------------------------------------------------------------------------
 
 
@@ -102,24 +102,18 @@ def _on_event(event: str, **_kw) -> None:
         _XLA_EVENTS[event] = _XLA_EVENTS.get(event, 0) + 1
 
 
-def install_monitoring() -> bool:
+def install_monitoring() -> None:
     """Subscribe to ``jax.monitoring`` events once per process.
 
-    Returns True when the listener is (already) installed.  Event names
-    are jax-internal and version-dependent; the counters are exported
-    verbatim under ``jax_monitoring_events_total{event=...}`` as
-    corroborating evidence next to the first-class ``note_trace``
-    counters, never as the primary signal."""
+    Event names are jax-internal; the counters are exported verbatim
+    under ``jax_monitoring_events_total{event=...}`` as corroborating
+    evidence next to the first-class ``note_trace`` counters, never as
+    the primary signal."""
     global _monitoring_installed
-    if _monitoring_installed:
-        return True
-    try:
+    if not _monitoring_installed:
         from jax import monitoring
         monitoring.register_event_listener(_on_event)
         _monitoring_installed = True
-    except Exception:   # noqa: BLE001 — older jax / no monitoring: degrade
-        return False
-    return True
 
 
 # ---------------------------------------------------------------------------

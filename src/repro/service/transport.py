@@ -6,7 +6,9 @@ tier, all stdlib-only:
 1. **Worker pools.**  ``ProcessWorkerPool`` spawns one ``worker.worker_main``
    subprocess per worker (``multiprocessing`` "spawn" — XLA runtime state
    must never cross a fork), each with its own task queue and one shared
-   result queue.  ``SimWorkerPool`` is a drop-in in-process stand-in with the
+   result queue.  It runs on the CPU backend only: one process owns a
+   chip, so on an accelerator the pool refuses to start.  ``SimWorkerPool``
+   is a drop-in in-process stand-in with the
    same five-call surface whose "workers" evaluate tasks synchronously
    through the *same* ``worker.eval_task`` code path, applying
    ``fault_events`` at the same dequeue points — so every recovery path is
@@ -57,6 +59,7 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..distributed.fault import Heartbeat, assign_shards
@@ -84,13 +87,24 @@ class ProcessWorkerPool:
 
     One task queue per worker plus one shared result queue; ``__init__``
     blocks until every worker says hello, so interpreter/jax boot time is
-    never mistaken for a stall by the scheduler's timeout."""
+    never mistaken for a stall by the scheduler's timeout.
+
+    Workers are a CPU-only fault-isolation tier: on an accelerator the
+    front end's process owns the chip and a worker could not reach it, so
+    the pool refuses to start unless the front end's backend is the CPU."""
 
     def __init__(self, n_workers: int, *,
                  fault_events: Sequence[Tuple[int, int, str, float]] = (),
                  start_method: str = "spawn",
                  ready_timeout_s: float = 300.0):
         import multiprocessing as mp
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"ProcessWorkerPool runs on the CPU backend only, and this "
+                f"process's backend is {backend!r}: the front end owns the "
+                f"device, so worker subprocesses could not use it.  Serve "
+                f"in-process (SubStratServer without a worker pool) instead.")
         if n_workers < 1:
             raise ValueError("need at least one worker")
         ctx = mp.get_context(start_method)

@@ -58,9 +58,7 @@ def test_compressed_psum_matches_plain():
         from repro.launch.mesh import make_mesh
         from repro.distributed.compression import compressed_psum
 
-        shard_map = getattr(jax, "shard_map", None)  # jax<0.6 compat
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.key(0), (8, 128), jnp.float32)

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.hlo_costs import analyze_hlo, xla_cost_dict
+from repro.launch.hlo_costs import analyze_hlo
 
 
 def _compile(f, *args):
@@ -27,7 +27,7 @@ def test_scanned_matmul_flops_exact():
     assert cost.n_while == 1
     assert list(cost.trip_counts.values()) == [L]
     # XLA's own analysis undercounts by ~L (this is why the engine exists)
-    xla = float(xla_cost_dict(c.cost_analysis()).get("flops", 0.0))
+    xla = float(c.cost_analysis().get("flops", 0.0))
     assert xla < expected / 2
 
 

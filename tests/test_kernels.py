@@ -46,7 +46,7 @@ def test_entropy_kernel_weight_dtypes(wdtype):
     np.testing.assert_allclose(np.asarray(h_k), np.asarray(h_r), atol=1e-4)
 
 
-@pytest.mark.parametrize("tile_n,tile_m", [(64, 2), (128, 8), (1024, 8)])
+@pytest.mark.parametrize("tile_n,tile_m", [(8, 128), (64, 128), (1024, 256)])
 def test_entropy_kernel_tile_sweep(tile_n, tile_m):
     rng = np.random.default_rng(7)
     codes = jnp.asarray(rng.integers(0, 32, (500, 9)), jnp.int32)
@@ -70,15 +70,30 @@ def test_entropy_kernel_property(N, M, B, seed):
     np.testing.assert_allclose(np.asarray(h_k), np.asarray(h_r), atol=1e-3)
 
 
+@pytest.mark.parametrize("tile_n,tile_m", [(4, 128), (8, 64), (12, 256)])
+def test_entropy_kernel_rejects_unaligned_tiles(tile_n, tile_m):
+    codes = jnp.zeros((16, 4), jnp.int32)
+    with pytest.raises(ValueError, match="multiple of"):
+        masked_histogram_pallas(codes, jnp.ones((16,)), 4,
+                                tile_n=tile_n, tile_m=tile_m)
+
+
 # padding edges (DESIGN.md §16.4): rows shorter than one tile, a ragged
-# column tile, and bins beyond every observed code (B > max(n_bins)) must
-# all agree compiled/interpret/jnp — padding lanes carry zero weight.
+# column tile, bins beyond every observed code (B > max(n_bins)), and the
+# (8, 128) tile edges themselves must match the jnp oracle bit for bit —
+# padding rows carry zero weight, padding columns and bins are sliced off.
+# Weights are a 0/1 membership mask, as Gen-DST uses them, so every count is
+# an exact small integer whatever the order of the adds.
 PADDING_EDGE_SHAPES = [
     # (N, M, B, code_max)
     (5, 3, 8, None),        # N=5 < tile_n — one mostly-padded row tile
-    (300, 13, 16, None),    # M=13 % tile_m=8 != 0 — ragged column tile
+    (300, 13, 16, None),    # M=13 < tile_m=128 — ragged column tile
     (200, 4, 64, 11),       # codes < 11 << B=64 — padding bins
     (7, 9, 32, 5),          # all three edges at once
+    (8, 128, 128, None),    # exactly one (8, 128) tile, B = one lane width
+    (9, 129, 129, None),    # one past the tile on every axis
+    (16, 256, 256, 200),    # two column tiles, B = 256 with padding bins
+    (24, 2300, 256, None),  # the D1 population fold: P * M = 100 * 23
 ]
 
 
@@ -86,7 +101,7 @@ def _padding_case(N, M, B, code_max):
     rng = np.random.default_rng(N * 7 + M)
     hi = B if code_max is None else code_max
     codes = jnp.asarray(rng.integers(0, hi, (N, M)), jnp.int32)
-    w = jnp.asarray(rng.random(N), jnp.float32)
+    w = jnp.asarray((rng.random(N) < 0.6).astype(np.float32))
     return codes, w
 
 
@@ -95,19 +110,20 @@ def test_histogram_padding_edges_interpret(N, M, B, code_max):
     codes, w = _padding_case(N, M, B, code_max)
     h_k = masked_histogram_pallas(codes, w, B, interpret=True)
     h_r = masked_histogram_ref(codes, w, B)
-    np.testing.assert_allclose(np.asarray(h_k), np.asarray(h_r), atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(h_k), np.asarray(h_r))
     if code_max is not None:  # bins no code can reach must stay empty
         assert not np.asarray(h_k)[:, code_max:].any()
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled Mosaic leg needs a TPU")
-@pytest.mark.parametrize("N,M,B,code_max", PADDING_EDGE_SHAPES)
-def test_histogram_padding_edges_compiled(N, M, B, code_max):
-    codes, w = _padding_case(N, M, B, code_max)
-    h_k = masked_histogram_pallas(codes, w, B, interpret=False)
-    h_r = masked_histogram_ref(codes, w, B)
-    np.testing.assert_allclose(np.asarray(h_k), np.asarray(h_r), atol=1e-4)
+def test_resolve_interpret_interprets_only_on_cpu(monkeypatch):
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret(None) is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True      # an explicit choice wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
 
 
 def test_column_entropy_masked_matches_measures():

@@ -304,3 +304,19 @@ def test_fault_event_validation():
         FaultEvent(-1, 0, "kill")
     compiled = FaultPlan.kill(1, 2).compile()
     assert compiled == ((1, 2, "kill", 0.0),)
+
+
+def test_process_pool_refuses_off_the_cpu_backend(monkeypatch):
+    """One process owns an accelerator: off the CPU backend the pool
+    raises before it spawns a worker, instead of leaving the workers to
+    fail and the front end to evaluate every rung itself."""
+    import multiprocessing
+    from repro.service import ProcessWorkerPool
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a worker was spawned")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
+    with pytest.raises(RuntimeError, match="CPU backend only.*'tpu'"):
+        ProcessWorkerPool(1)
