@@ -1,6 +1,6 @@
-"""JAX compile/dispatch accounting (DESIGN.md §15.4).
+"""JAX compile accounting (DESIGN.md §15.4).
 
-Three small instruments, all process-global (compilation caches are):
+Two small instruments, all process-global (compilation caches are):
 
 **Retracing counters.**  Every jitted hot-path kernel calls
 ``note_trace("<site>")`` as its first statement.  A jitted function body
@@ -18,32 +18,20 @@ every trial costs the group-maximal padded shape at the group-maximal
 scan length, its useful work is its own shape at its own step budget —
 the absolute-FLOPs companion of the scheduler's relative ``merge_waste``
 ratio, built on ``launch/flops.py``'s analytic ``tabular_trial_flops``.
-
-**Dispatch profile hook.**  Opt-in: ``set_dispatch_hook(fn)`` installs a
-callable that receives ``(name, seconds, meta)`` after every scheduler
-dispatch — the seam for wiring ``jax.profiler`` traces or external
-telemetry to exactly the dispatches of interest without patching the
-scheduler.  ``install_monitoring()`` additionally subscribes to
-``jax.monitoring`` events so XLA's own compile events land in the same
-exposition.
 """
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, Optional, Sequence
+from typing import Dict, Sequence
 
 from .metrics import render_exposition_line
 
-__all__ = ["dispatch_event", "install_monitoring", "new_tracings_since",
-           "note_trace", "pack_flops", "render_prometheus", "reset_tracing",
-           "set_dispatch_hook", "total_tracings", "tracing_counts",
-           "tracing_snapshot"]
+__all__ = ["new_tracings_since", "note_trace", "pack_flops",
+           "render_prometheus", "reset_tracing", "total_tracings",
+           "tracing_counts", "tracing_snapshot"]
 
 _lock = threading.Lock()
 _TRACE_COUNTS: Dict[str, int] = {}
-_XLA_EVENTS: Dict[str, int] = {}
-_monitoring_installed = False
-_dispatch_hook: Optional[Callable] = None
 
 
 # ---------------------------------------------------------------------------
@@ -93,30 +81,6 @@ def reset_tracing() -> None:
 
 
 # ---------------------------------------------------------------------------
-# jax.monitoring bridge
-# ---------------------------------------------------------------------------
-
-
-def _on_event(event: str, **_kw) -> None:
-    with _lock:
-        _XLA_EVENTS[event] = _XLA_EVENTS.get(event, 0) + 1
-
-
-def install_monitoring() -> None:
-    """Subscribe to ``jax.monitoring`` events once per process.
-
-    Event names are jax-internal; the counters are exported verbatim
-    under ``jax_monitoring_events_total{event=...}`` as corroborating
-    evidence next to the first-class ``note_trace`` counters, never as
-    the primary signal."""
-    global _monitoring_installed
-    if not _monitoring_installed:
-        from jax import monitoring
-        monitoring.register_event_listener(_on_event)
-        _monitoring_installed = True
-
-
-# ---------------------------------------------------------------------------
 # megabatch FLOP accounting
 # ---------------------------------------------------------------------------
 
@@ -144,35 +108,15 @@ def pack_flops(metas: Sequence) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# per-dispatch profile hook (opt-in)
-# ---------------------------------------------------------------------------
-
-
-def set_dispatch_hook(fn: Optional[Callable]) -> None:
-    """Install (or clear, with None) the per-dispatch profile callback:
-    ``fn(name, seconds, meta)`` fires after every scheduler dispatch."""
-    global _dispatch_hook
-    _dispatch_hook = fn
-
-
-def dispatch_event(name: str, seconds: float, **meta) -> None:
-    """Report one finished dispatch to the opt-in hook (no-op otherwise)."""
-    hook = _dispatch_hook
-    if hook is not None:
-        hook(name, seconds, meta)
-
-
-# ---------------------------------------------------------------------------
 # exposition
 # ---------------------------------------------------------------------------
 
 
 def render_prometheus() -> str:
-    """Prometheus text block for the process-global jit/XLA counters —
+    """Prometheus text block for the process-global jit-tracing counters —
     appended to the scheduler registry's exposition by ``/v1/metrics``."""
     with _lock:
         traces = sorted(_TRACE_COUNTS.items())
-        events = sorted(_XLA_EVENTS.items())
     lines = [
         "# HELP jax_jit_tracings_total jit tracings per instrumented "
         "call-site (1 per compilation-cache miss)",
@@ -184,10 +128,4 @@ def render_prometheus() -> str:
     if not traces:
         lines.append(render_exposition_line(
             "jax_jit_tracings_total", [("site", "none")], 0.0))
-    lines.append("# HELP jax_monitoring_events_total raw jax.monitoring "
-                 "event counts (best-effort corroboration)")
-    lines.append("# TYPE jax_monitoring_events_total counter")
-    lines.extend(render_exposition_line("jax_monitoring_events_total",
-                                        [("event", ev)], float(n))
-                 for ev, n in events)
     return "\n".join(lines) + "\n"

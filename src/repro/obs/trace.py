@@ -22,17 +22,43 @@ are passed explicitly (``parent_id=``).
 Timestamps are wall-clock (``time.time()``): worker and front-end spans
 from the same machine line up on one timeline, which is how
 ``render_timeline`` shows queue-wait next to remote evaluation.
+
+**The profiler's clock.**  A span opened with ``layer=`` also holds a
+``jax.profiler.TraceAnnotation`` of that name while it is open, and
+``annotate(layer)`` holds one for process-level work that belongs to no
+job.  The host interval then lands in the profiler's own trace, on the
+thread that did the work and on the clock of the device's programs, so a
+device-idle interval can be charged to the layer the host was in.  With
+the profiler off an annotation costs under a microsecond.  The fixed set
+of layer names (DESIGN.md §15.1):
+
+- ``drive.wait``: the HTTP driver's admission-grace sleep, wake wait and
+  wait for the front end's lock;
+- ``drive.step``: one ``Scheduler.step``;
+- ``factorize`` (holding ``cache_probe``), ``gen_dst``, ``automl.init``,
+  ``automl.rung``, ``automl.finish``: the scheduler's phase work;
+- ``http.decode``, ``http.lock``: a handler decoding a submit payload, and
+  waiting for the front end's lock.
+
+The profiler names a thread's host line after the thread's OS-level name,
+which Python leaves as the program's own for every thread;
+``name_thread`` gives a long-lived thread (the HTTP driver) a line of its
+own name.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import ctypes
 import hashlib
+import sys
 import time
 from typing import Dict, Iterable, List, Optional
 
-__all__ = ["child_ctx", "current_span", "job_trace_id", "make_span",
-           "render_timeline", "span", "span_id"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["annotate", "child_ctx", "current_span", "job_trace_id",
+           "make_span", "name_thread", "render_timeline", "span", "span_id"]
 
 _CURRENT: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "substrat_current_span", default=None)
@@ -77,14 +103,36 @@ def make_span(trace_id: str, name: str, t0: float, t1: float, *,
     }
 
 
+def annotate(layer: str) -> TraceAnnotation:
+    """The profiler annotation of ``layer``: a context manager that writes
+    the host interval it covers into ``jax.profiler``'s trace."""
+    return TraceAnnotation(layer)
+
+
+def name_thread(name: str) -> None:
+    """Set the calling thread's OS-level name (Linux; at most 15 bytes),
+    the name its host line carries in a profiler trace, ``top -H`` and
+    debuggers.  Elsewhere a no-op."""
+    if not sys.platform.startswith("linux"):
+        return
+    libc = ctypes.CDLL(None)
+    libc.pthread_self.argtypes = []
+    libc.pthread_self.restype = ctypes.c_ulong
+    libc.pthread_setname_np.argtypes = [ctypes.c_ulong, ctypes.c_char_p]
+    libc.pthread_setname_np.restype = ctypes.c_int
+    libc.pthread_setname_np(libc.pthread_self(), name.encode()[:15])
+
+
 @contextlib.contextmanager
 def span(sink: Optional[List[dict]], trace_id: str, name: str, *,
-         attempt: int = 0, parent_id: Optional[str] = None, **attrs):
+         attempt: int = 0, parent_id: Optional[str] = None,
+         layer: Optional[str] = None, **attrs):
     """Open a span; on exit, close it and append to ``sink``.
 
     The parent defaults to the contextvar current span (same-context
     nesting); pass ``parent_id=`` explicitly when the parent lives in
-    another process (the wire-propagated dispatch span).  The open span
+    another process (the wire-propagated dispatch span).  With ``layer``
+    the span also holds ``annotate(layer)`` while open.  The open span
     dict is yielded so callers can add attrs mid-flight."""
     if parent_id is None:
         parent = _CURRENT.get()
@@ -93,7 +141,8 @@ def span(sink: Optional[List[dict]], trace_id: str, name: str, *,
                    parent_id=parent_id, attempt=attempt, attrs=attrs)
     token = _CURRENT.set(sp)
     try:
-        yield sp
+        with annotate(layer) if layer else contextlib.nullcontext():
+            yield sp
     except BaseException:
         sp["attrs"]["error"] = True
         raise

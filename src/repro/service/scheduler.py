@@ -62,6 +62,7 @@ and ``Plan(warm_start=False)`` jobs run the unchanged full population.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -333,6 +334,15 @@ class Scheduler:
         self.m_experience_datasets = m.gauge(
             "experience_datasets",
             "distinct trained fingerprints in the experience store")
+        # the HTTP front end's (transport.SubStratHTTPServer) counters live
+        # here so that /v1/metrics exports them and snapshots keep them
+        self.m_http_lock_wait = m.counter(
+            "http_lock_wait_seconds_total",
+            "seconds HTTP handlers waited for the front end's lock",
+            ("route",))
+        self.m_submit_decode = m.counter(
+            "submit_decode_seconds_total",
+            "seconds the front end spent decoding submit payloads")
 
     @property
     def hetero_pad_limit(self) -> float:
@@ -385,16 +395,33 @@ class Scheduler:
 
     # -- phase work ---------------------------------------------------------
 
-    def _job_time_span(self, job: SubStratJob, name: str, key: str,
-                       w0: float, seconds: float, **attrs) -> None:
-        """Record one closed span on the job's trace AND fold its cost into
+    @contextlib.contextmanager
+    def _job_time_span(self, job: SubStratJob, name: str, key: str, *,
+                       layer: Optional[str] = None, **attrs):
+        """Wrap one unit of a job's work in a span on the job's trace (with
+        ``layer``, also a profiler annotation) AND fold its seconds into
         ``job.times[key]`` — the span record is the phase-time bookkeeping
-        (DESIGN.md §15.1), not a parallel ledger.  ``seconds`` may be an
-        attributed equal share of a merged dispatch rather than the span's
-        own wall extent; the span keeps both (extent in t0/t1, share in
-        attrs)."""
+        (DESIGN.md §15.1), not a parallel ledger."""
+        t0 = time.perf_counter()
+        with trace.span(job.spans, job.trace_id, name, layer=layer,
+                        seconds=0.0, **attrs) as sp:
+            try:
+                yield sp
+            finally:
+                seconds = time.perf_counter() - t0
+                sp["attrs"]["seconds"] = seconds
+                job.times[key] = job.times.get(key, 0.0) + seconds
+
+    @staticmethod
+    def _record_job_span(job: SubStratJob, name: str, key: str,
+                         window: Tuple[float, float], seconds: float,
+                         **attrs) -> None:
+        """Record a job's part of a dispatch it shared: the span's extent is
+        the dispatch's own window (wall clock, taken around the work),
+        ``seconds`` the job's equal share of it, which is what
+        ``job.times[key]`` is charged."""
         job.spans.append(trace.make_span(
-            job.trace_id, name, w0, time.time(),
+            job.trace_id, name, window[0], window[1],
             attrs={"seconds": float(seconds), **attrs}))
         job.times[key] = job.times.get(key, 0.0) + float(seconds)
 
@@ -413,48 +440,47 @@ class Scheduler:
                 job.spans.append(cp)
 
     def _factorize(self, job: SubStratJob) -> None:
-        t0 = time.perf_counter()
-        w0 = time.time()
-        if job.coded is None:
-            job.coded = factorize(job.X, job.y)
-        job.fingerprint = dataset_fingerprint(job.coded)
-        if self.warm_start:
-            # register the dataset's meta-feature vector (free: derived
-            # from the codes just factorized, sharing the DST entropy trace)
-            self.experience.note_meta(job.fingerprint,
-                                      meta_features(job.coded))
-        self._job_time_span(job, "factorize", "factorize_s", w0,
-                            time.perf_counter() - t0, phase="factorize")
+        with trace.annotate("factorize"):
+            with self._job_time_span(job, "factorize", "factorize_s",
+                                     phase="factorize"):
+                if job.coded is None:
+                    job.coded = factorize(job.X, job.y)
+                job.fingerprint = dataset_fingerprint(job.coded)
+                if self.warm_start:
+                    # register the dataset's meta-feature vector (free:
+                    # derived from the codes just factorized, sharing the
+                    # DST entropy trace)
+                    self.experience.note_meta(job.fingerprint,
+                                              meta_features(job.coded))
 
-        # the cache key is the plan's resolved subset identity — the actual
-        # search problem, not the (possibly None) plan fields
-        if job.plan.cacheable:
-            n, m, strategy, opts = job.plan.subset_identity(job.coded)
-            job.cache_key = dst_cache_key(
-                job.fingerprint, n, m, _plan_measure(job.plan),
-                search_cfg=(strategy, opts))
+            # the cache key is the plan's resolved subset identity — the
+            # actual search problem, not the (possibly None) plan fields
+            if job.plan.cacheable:
+                n, m, strategy, opts = job.plan.subset_identity(job.coded)
+                job.cache_key = dst_cache_key(
+                    job.fingerprint, n, m, _plan_measure(job.plan),
+                    search_cfg=(strategy, opts))
 
-        if not self._try_cache_hit(job):
-            if job.cache_key is not None:
-                self.m_cache_misses.inc()
-            job.phase = "dst"
+            if not self._try_cache_hit(job):
+                if job.cache_key is not None:
+                    self.m_cache_misses.inc()
+                job.phase = "dst"
 
     def _try_cache_hit(self, job: SubStratJob) -> bool:
         """Probe the DST cache; on a hit, install the stored subset and
         advance the job past the subset search (and, when warm-startable,
         past the sub-AutoML pass)."""
-        t0 = time.perf_counter()
-        w0 = time.time()
         entry = self.cache.get(job.cache_key) if job.cache_key else None
         if entry is None:
             return False
         # cache hit: the stored subset replaces the whole strategy search;
-        # gen_dst_s records what the hit actually cost (the lookup)
-        job.cache_hit = True
-        self.m_cache_hits.inc()
-        self._install_subset(job, entry.row_idx, entry.col_mask, entry.fitness)
-        self._job_time_span(job, "cache_probe", "gen_dst_s", w0,
-                            time.perf_counter() - t0, cache_hit=True)
+        # gen_dst_s records what the hit actually cost (installing it)
+        with self._job_time_span(job, "cache_probe", "gen_dst_s",
+                                 layer="cache_probe", cache_hit=True):
+            job.cache_hit = True
+            self.m_cache_hits.inc()
+            self._install_subset(job, entry.row_idx, entry.col_mask,
+                                 entry.fitness)
         if self.warm_start and job.plan.fine_tune and entry.winner_family:
             job.warm_family = entry.winner_family
             job.phase = "fine_tune"
@@ -516,11 +542,6 @@ class Scheduler:
     def _record_subset(self, job: SubStratJob, subset, elapsed: float) -> None:
         self._install_subset(job, subset.row_idx, subset.col_mask,
                              subset.fitness)
-        # the span's extent approximates the dispatch window (batched
-        # searches hand each rep its equal share, not its own wall clock)
-        self._job_time_span(job, "gen_dst", "gen_dst_s",
-                            time.time() - elapsed, elapsed,
-                            phase="dst", strategy=job.strategy_name)
         if job.cache_key is not None:
             self.cache.put(job.cache_key, DSTCacheEntry(
                 row_idx=job.row_idx, col_mask=job.col_mask,
@@ -531,10 +552,11 @@ class Scheduler:
         if self._reprobe(job):
             return
         p = job.plan
-        t0 = time.perf_counter()
-        subset = run_strategy(p.strategy, job.key, job.coded, p.n, p.m,
-                              p.strategy_opts)
-        self._record_subset(job, subset, time.perf_counter() - t0)
+        with self._job_time_span(job, "gen_dst", "gen_dst_s", layer="gen_dst",
+                                 phase="dst", strategy=job.strategy_name) as sp:
+            subset = run_strategy(p.strategy, job.key, job.coded, p.n, p.m,
+                                  p.strategy_opts)
+        self._record_subset(job, subset, sp["attrs"]["seconds"])
 
     def _dst_batch_key(self, job: SubStratJob):
         """Hashable batch-compatibility class of a job's subset search, or
@@ -588,10 +610,12 @@ class Scheduler:
             else:
                 strategy, opts, n, m = bkey[0], bkey[1], bkey[2], bkey[3]
                 t0 = time.perf_counter()
+                w0 = time.time()
                 try:
-                    subsets = run_strategy_batch(
-                        strategy, [j.key for j in reps],
-                        [j.coded for j in reps], n, m, opts)
+                    with trace.annotate("gen_dst"):
+                        subsets = run_strategy_batch(
+                            strategy, [j.key for j in reps],
+                            [j.coded for j in reps], n, m, opts)
                 except Exception as e:   # noqa: BLE001
                     # fail the reps only: followers fall through to the
                     # solo retry below (a batch failure, e.g. OOM on the
@@ -603,7 +627,11 @@ class Scheduler:
                 else:
                     self.merged_dst += len(reps)
                 share = (time.perf_counter() - t0) / max(len(subsets), 1)
+                window = (w0, time.time())
                 for job, subset in zip(reps, subsets):
+                    self._record_job_span(job, "gen_dst", "gen_dst_s", window,
+                                          share, phase="dst",
+                                          strategy=job.strategy_name)
                     self._record_subset(job, subset, share)
             for job in followers:   # their rep just populated the cache
                 if not self._reprobe(job):
@@ -641,55 +669,55 @@ class Scheduler:
     def _ensure_search(self, job: SubStratJob) -> None:
         if job.search is not None:
             return
-        t0 = time.perf_counter()
-        w0 = time.time()
         p = job.plan
-        if job.phase == "sub_automl":
-            X_sub, y_sub = build_subset(job.X, job.y, job.row_idx, job.col_idx,
-                                        job.key)
-            job.y_sub = y_sub
-            seeds = self._portfolio_seeds(job)
-            job.search = search_init(
-                X_sub, y_sub, config=p.resolved_sub_automl(),
-                seed_trials=seeds)
-            if seeds:
-                saved = len(job.search.specs) - len(job.search.alive_ids)
-                if saved > 0:
-                    self.m_portfolio_saved.inc(saved)
-        else:   # fine_tune: restricted to M''s (or the cache-known) family
-            family = job.warm_family or job.intermediate.spec.family
-            job.search = search_init(
-                job.X, job.y, config=p.resolved_ft_automl(),
-                restrict_family=family)
-        self._job_time_span(job, f"{job.phase}/init",
-                            _PHASE_TIME_KEY[job.phase], w0,
-                            time.perf_counter() - t0, phase=job.phase)
+        with self._job_time_span(job, f"{job.phase}/init",
+                                 _PHASE_TIME_KEY[job.phase],
+                                 layer="automl.init", phase=job.phase):
+            if job.phase == "sub_automl":
+                X_sub, y_sub = build_subset(job.X, job.y, job.row_idx,
+                                            job.col_idx, job.key)
+                job.y_sub = y_sub
+                seeds = self._portfolio_seeds(job)
+                job.search = search_init(
+                    X_sub, y_sub, config=p.resolved_sub_automl(),
+                    seed_trials=seeds)
+                if seeds:
+                    saved = len(job.search.specs) - len(job.search.alive_ids)
+                    if saved > 0:
+                        self.m_portfolio_saved.inc(saved)
+            else:   # fine_tune: restricted to M''s (or the cache-known) family
+                family = job.warm_family or job.intermediate.spec.family
+                job.search = search_init(
+                    job.X, job.y, config=p.resolved_ft_automl(),
+                    restrict_family=family)
 
     def _finish_search(self, job: SubStratJob) -> None:
-        if job.phase == "sub_automl":
-            job.intermediate = search_result(job.search)
-            job.search = None
-            if job.cache_key is not None:
-                self.cache.note_winner(job.cache_key,
-                                       job.intermediate.spec.family)
-            if self.warm_start and job.fingerprint is not None:
-                # the fingerprint's history is now usable warm-start
-                # material (trained() requires a winner)
-                self.experience.note_winner(job.fingerprint,
-                                            job.intermediate.spec)
-                self.m_experience_datasets.set(self.experience.n_trained())
-            if job.plan.fine_tune:
-                job.phase = "fine_tune"
-                return
-            final = job.intermediate
-            if job.X_test is not None:
-                final = nf_test_eval(job.intermediate, job.y_sub, job.col_idx,
-                                     job.X_test, job.y_test)
-            job.final = final
-        else:
-            job.final = search_result(job.search, job.X_test, job.y_test)
-            job.search = None
-        self._complete(job)
+        with trace.annotate("automl.finish"):
+            if job.phase == "sub_automl":
+                job.intermediate = search_result(job.search)
+                job.search = None
+                if job.cache_key is not None:
+                    self.cache.note_winner(job.cache_key,
+                                           job.intermediate.spec.family)
+                if self.warm_start and job.fingerprint is not None:
+                    # the fingerprint's history is now usable warm-start
+                    # material (trained() requires a winner)
+                    self.experience.note_winner(job.fingerprint,
+                                                job.intermediate.spec)
+                    self.m_experience_datasets.set(
+                        self.experience.n_trained())
+                if job.plan.fine_tune:
+                    job.phase = "fine_tune"
+                    return
+                final = job.intermediate
+                if job.X_test is not None:
+                    final = nf_test_eval(job.intermediate, job.y_sub,
+                                         job.col_idx, job.X_test, job.y_test)
+                job.final = final
+            else:
+                job.final = search_result(job.search, job.X_test, job.y_test)
+                job.search = None
+            self._complete(job)
 
     def _complete(self, job: SubStratJob) -> None:
         job.result = SubStratResult(
@@ -788,9 +816,10 @@ class Scheduler:
         })
 
     def _record_group(self, group: List[SubStratJob], cohorts, outs,
-                      share: float) -> None:
-        """Record one successful dispatch: merge counters, per-job rung
-        results, equal-share wall-time attribution, leaderboard entries."""
+                      share: float, window: Tuple[float, float]) -> None:
+        """Record one successful dispatch, which ran over the wall-clock
+        ``window``: merge counters, per-job rung results, equal-share
+        wall-time attribution, leaderboard entries."""
         if len(group) > 1:
             self.merged_rungs += 1
             self.merged_jobs += len(group)
@@ -803,15 +832,12 @@ class Scheduler:
         wall = share * len(group)
         self.m_dispatches.inc(mode=mode)
         self.m_dispatch_latency.observe(wall, mode=mode)
-        jaxprof.dispatch_event("rung_dispatch", wall,
-                               mode=mode, jobs=len(group))
-        w0 = time.time() - wall   # the dispatch window just ended
         for job, (scored, positions) in zip(group, outs):
             search_record(job.search, scored, positions, share)
             rung = job.search.rung_i - 1   # search_record advanced past it
-            self._job_time_span(job, f"{job.phase}/rung{rung}",
-                                _PHASE_TIME_KEY[job.phase], w0, share,
-                                phase=job.phase, rung=rung, mode=mode)
+            self._record_job_span(job, f"{job.phase}/rung{rung}",
+                                  _PHASE_TIME_KEY[job.phase], window, share,
+                                  phase=job.phase, rung=rung, mode=mode)
             self._note_rung(job)
 
     def _isolate_failure(self, group: List[SubStratJob], cohorts,
@@ -830,14 +856,17 @@ class Scheduler:
     def _run_merged(self, group: List[SubStratJob], cohorts, eval_fn) -> None:
         """Dispatch one packed group through ``eval_fn`` and record every
         job's rung; merged wall time is shared equally by participants."""
-        t0 = time.perf_counter()
-        try:
-            outs = eval_fn(cohorts)
-        except Exception as e:   # noqa: BLE001 — isolate job failures
-            self._isolate_failure(group, cohorts, eval_fn, e)
-            return
-        self._record_group(group, cohorts, outs,
-                           (time.perf_counter() - t0) / len(group))
+        with trace.annotate("automl.rung"):
+            t0 = time.perf_counter()
+            w0 = time.time()
+            try:
+                outs = eval_fn(cohorts)
+            except Exception as e:   # noqa: BLE001 — isolate job failures
+                self._isolate_failure(group, cohorts, eval_fn, e)
+                return
+            self._record_group(group, cohorts, outs,
+                               (time.perf_counter() - t0) / len(group),
+                               (w0, time.time()))
 
     def _eval_groups(self, packed, eval_fn) -> None:
         """Execute packed rung groups — the transport hook (DESIGN.md §14.3).
@@ -873,21 +902,20 @@ class Scheduler:
             solo.extend(singles)
 
         for job in solo:
-            t0 = time.perf_counter()
-            w0 = time.time()
+            rung = job.search.rung_i   # search_eval_rung advances past it
             try:
-                search_eval_rung(job.search)
+                with self._job_time_span(
+                        job, f"{job.phase}/rung{rung}",
+                        _PHASE_TIME_KEY[job.phase], layer="automl.rung",
+                        phase=job.phase, rung=rung, mode="solo") as sp:
+                    search_eval_rung(job.search)
             except Exception as e:   # noqa: BLE001 — isolate job failures
                 self._fail(job, e)
                 continue
-            dt = time.perf_counter() - t0
             self.solo_rungs += 1
             self.m_dispatches.inc(mode="solo")
-            self.m_dispatch_latency.observe(dt, mode="solo")
-            rung = job.search.rung_i - 1
-            self._job_time_span(job, f"{job.phase}/rung{rung}",
-                                _PHASE_TIME_KEY[job.phase], w0, dt,
-                                phase=job.phase, rung=rung, mode="solo")
+            self.m_dispatch_latency.observe(sp["attrs"]["seconds"],
+                                            mode="solo")
             self._note_rung(job)
 
         if mega:
@@ -920,47 +948,49 @@ class Scheduler:
     def step(self) -> bool:
         """Advance every active job one phase unit.  Returns True iff any
         work was done (False means nothing is pending)."""
-        worked = False
-        dst_ready: List[SubStratJob] = []
-        for job in sorted(self.pending(), key=lambda j: j.job_id):
-            try:
-                if job.phase == "factorize":
-                    self._factorize(job)
+        with trace.annotate("drive.step"):
+            worked = False
+            dst_ready: List[SubStratJob] = []
+            for job in sorted(self.pending(), key=lambda j: j.job_id):
+                try:
+                    if job.phase == "factorize":
+                        self._factorize(job)
+                        worked = True
+                except Exception as e:   # noqa: BLE001 — isolate job failures
+                    self._fail(job, e)
                     worked = True
-            except Exception as e:   # noqa: BLE001 — isolate job failures
-                self._fail(job, e)
+                if job.phase == "dst":
+                    dst_ready.append(job)
+            if dst_ready:
+                self._dispatch_dst(dst_ready)
                 worked = True
-            if job.phase == "dst":
-                dst_ready.append(job)
-        if dst_ready:
-            self._dispatch_dst(dst_ready)
-            worked = True
 
-        ready: List[SubStratJob] = []
-        for job in sorted(self.pending(), key=lambda j: j.job_id):
-            if job.phase not in ("sub_automl", "fine_tune"):
-                continue
-            try:
-                self._ensure_search(job)
-            except Exception as e:   # noqa: BLE001
-                self._fail(job, e)
+            ready: List[SubStratJob] = []
+            for job in sorted(self.pending(), key=lambda j: j.job_id):
+                if job.phase not in ("sub_automl", "fine_tune"):
+                    continue
+                try:
+                    self._ensure_search(job)
+                except Exception as e:   # noqa: BLE001
+                    self._fail(job, e)
+                    worked = True
+                    continue
+                ready.append(job)
+            if ready:
+                self._dispatch_rungs(ready)
                 worked = True
-                continue
-            ready.append(job)
-        if ready:
-            self._dispatch_rungs(ready)
-            worked = True
-            for job in ready:
-                if job.active and job.search is not None and job.search.done:
-                    try:
-                        self._finish_search(job)
-                    except Exception as e:   # noqa: BLE001
-                        self._fail(job, e)
-        # release warm-waiters last, so the step that publishes a winner
-        # family also un-parks the jobs waiting on it
-        if self._advance_waiters():
-            worked = True
-        return worked
+                for job in ready:
+                    if (job.active and job.search is not None
+                            and job.search.done):
+                        try:
+                            self._finish_search(job)
+                        except Exception as e:   # noqa: BLE001
+                            self._fail(job, e)
+            # release warm-waiters last, so the step that publishes a winner
+            # family also un-parks the jobs waiting on it
+            if self._advance_waiters():
+                worked = True
+            return worked
 
     def run(self) -> None:
         """Drive all pending jobs to completion."""

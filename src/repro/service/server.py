@@ -317,7 +317,7 @@ class SubStratServer:
 
     def metrics_text(self) -> str:
         """Prometheus text exposition: the scheduler's registry plus the
-        process-global JAX compile/dispatch counters (``GET /v1/metrics``)."""
+        process-global jit-tracing counters (``GET /v1/metrics``)."""
         from ..obs import jaxprof
         return self.scheduler.metrics.render() + jaxprof.render_prometheus()
 

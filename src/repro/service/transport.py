@@ -47,6 +47,7 @@ tier, all stdlib-only:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -63,7 +64,7 @@ import jax
 import numpy as np
 
 from ..distributed.fault import Heartbeat, assign_shards
-from ..obs import jaxprof, trace
+from ..obs import trace
 from . import wire
 from .scheduler import Scheduler
 from .server import RateLimited, SubStratServer
@@ -327,15 +328,18 @@ class DistributedScheduler(Scheduler):
                 {"kind": kind,
                  "cohorts": [cohort_payload(tc) for tc in cohorts]},
                 kind="task", trace=trace.child_ctx(ttrace, "dispatch"))
-        results = self._run_remote(payloads,
-                                   {tid: len(g) for tid, (g, _) in
-                                    enumerate(packed)},
-                                   task_traces)
+        w0 = time.time()
+        with trace.annotate("automl.rung"):   # the driver waits on the pool
+            results = self._run_remote(payloads,
+                                       {tid: len(g) for tid, (g, _) in
+                                        enumerate(packed)},
+                                       task_traces)
+        window = (w0, time.time())
         for tid, (group, cohorts) in enumerate(packed):
             status, val, share, spans = results[tid]
             self._fold_task_spans(group, spans)
             if status == "ok":
-                self._record_group(group, cohorts, val, share)
+                self._record_group(group, cohorts, val, share, window)
             else:
                 # remote failure: same blame isolation as in-process (a
                 # poison job must not doom its co-riders); the solo retries
@@ -475,9 +479,6 @@ class DistributedScheduler(Scheduler):
                     _close_dispatch(tid, "ok" if op == "done" else "error")
                     self.m_dispatches.inc(mode="remote")
                     self.m_dispatch_latency.observe(dt, mode="remote")
-                    jaxprof.dispatch_event("remote_dispatch", dt,
-                                           worker=int(w),
-                                           attempt=attempts[tid])
                     if op == "done":
                         outs = wire.loads(msg[3])
                         results[tid] = ("ok", outs, dt / group_sizes[tid])
@@ -594,7 +595,8 @@ def _send_text(handler, code: int, text: str, content_type: str) -> None:
 class SubStratHTTPServer:
     """HTTP transport in front of a ``SubStratServer`` (DESIGN.md §14.6).
 
-    Endpoints (all state touched under one lock; a single driver thread
+    Endpoints (all state touched under one lock, whose waits handlers count
+    in ``http_lock_wait_seconds_total{route}``; a single driver thread
     steps the scheduler whenever jobs are pending):
 
     - ``POST /v1/submit`` — wire payload ``{"X", "y", "tenant", "key",
@@ -606,7 +608,7 @@ class SubStratHTTPServer:
       the job is still running, ``500`` with the error if it failed
     - ``GET /v1/stats`` — JSON scheduler + tenant statistics
     - ``GET /v1/metrics`` — Prometheus text exposition (scheduler registry
-      + process-global jit/XLA counters; DESIGN.md §15.3)
+      + process-global jit-tracing counters; DESIGN.md §15.3)
     - ``GET /v1/trace?job_id=N`` — JSON span records of one job's timeline
     """
 
@@ -650,16 +652,37 @@ class SubStratHTTPServer:
         return self
 
     def _drive(self) -> None:
+        trace.name_thread("substrat-drive")   # its own line in a profile
         while not self._stop.is_set():
-            if time.monotonic() - self._last_submit < self.admission_grace_s:
-                time.sleep(self.admission_grace_s / 5)
-                continue
-            with self._lock:
+            with trace.annotate("drive.wait"):
+                if (time.monotonic() - self._last_submit
+                        < self.admission_grace_s):
+                    time.sleep(self.admission_grace_s / 5)
+                    continue
+                self._lock.acquire()
+            try:
                 worked = (self.server.scheduler.step()
                           if self.server.scheduler.pending() else False)
+            finally:
+                self._lock.release()
             if not worked:
-                self._wake.wait(timeout=0.05)
+                with trace.annotate("drive.wait"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
+
+    @contextlib.contextmanager
+    def _locked(self, route: str):
+        """Hold the front end's lock for a handler, counting the wait in
+        ``http_lock_wait_seconds_total{route}``."""
+        t0 = time.perf_counter()
+        with trace.annotate("http.lock"):
+            self._lock.acquire()
+        try:
+            self.server.scheduler.m_http_lock_wait.inc(
+                time.perf_counter() - t0, route=route)
+            yield
+        finally:
+            self._lock.release()
 
     def close(self) -> None:
         self._stop.set()
@@ -678,10 +701,15 @@ class SubStratHTTPServer:
             route = (method, parsed.path)
             if route == ("POST", "/v1/submit"):
                 length = int(handler.headers.get("Content-Length", 0))
-                req = wire.loads(handler.rfile.read(length))
+                body = handler.rfile.read(length)
+                t0 = time.perf_counter()
+                with trace.annotate("http.decode"):
+                    req = wire.loads(body)
+                self.server.scheduler.m_submit_decode.inc(
+                    time.perf_counter() - t0)
                 self._last_submit = time.monotonic()
                 try:
-                    with self._lock:
+                    with self._locked(parsed.path):
                         job_id = self.server.submit(
                             req["X"], req["y"],
                             tenant=req.get("tenant") or "default",
@@ -700,12 +728,12 @@ class SubStratHTTPServer:
             elif route == ("GET", "/v1/poll"):
                 job_id = int(qs["job_id"])
                 since = int(qs.get("since", 0))
-                with self._lock:
+                with self._locked(parsed.path):
                     status = self.server.poll(job_id, since=since)
                 _send_json(handler, 200, dataclasses.asdict(status))
             elif route == ("GET", "/v1/result"):
                 job_id = int(qs["job_id"])
-                with self._lock:
+                with self._locked(parsed.path):
                     job = self.server.scheduler.jobs.get(job_id)
                     if job is None:
                         _send_json(handler, 404,
@@ -718,17 +746,17 @@ class SubStratHTTPServer:
                         _send_wire(handler, 200,
                                    wire.dumps(job.result, kind="result"))
             elif route == ("GET", "/v1/stats"):
-                with self._lock:
+                with self._locked(parsed.path):
                     stats = self.server.stats()
                 _send_json(handler, 200, stats)
             elif route == ("GET", "/v1/metrics"):
-                with self._lock:
+                with self._locked(parsed.path):
                     text = self.server.metrics_text()
                 _send_text(handler, 200, text,
                            "text/plain; version=0.0.4; charset=utf-8")
             elif route == ("GET", "/v1/trace"):
                 job_id = int(qs["job_id"])
-                with self._lock:
+                with self._locked(parsed.path):
                     payload = self.server.trace(job_id)
                 if payload is None:
                     _send_json(handler, 404,

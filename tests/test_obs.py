@@ -6,6 +6,8 @@ so the retry-span and heartbeat-miss assertions have zero timing
 dependence.
 """
 import json
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -18,7 +20,8 @@ from repro.core.plan import execute, plan
 from repro.obs import jaxprof, trace
 from repro.obs.metrics import MetricsRegistry
 from repro.service import (
-    DistributedScheduler, SimWorkerPool, SubStratServer, wire,
+    DistributedScheduler, SimWorkerPool, SubStratHTTPClient,
+    SubStratHTTPServer, SubStratServer, wire,
 )
 from repro.service.cache import DSTCache
 from repro.service.scheduler import CohortMeta, Scheduler
@@ -178,17 +181,6 @@ def test_pack_flops_padded_vs_useful():
     assert padded == 2 * tabular_trial_flops(64, 16, 8, 3, 4)
 
 
-def test_dispatch_hook_opt_in():
-    seen = []
-    jaxprof.set_dispatch_hook(lambda name, s, meta: seen.append((name, meta)))
-    try:
-        jaxprof.dispatch_event("rung_dispatch", 0.1, mode="solo")
-    finally:
-        jaxprof.set_dispatch_hook(None)
-    jaxprof.dispatch_event("ignored", 0.1)
-    assert seen == [("rung_dispatch", {"mode": "solo"})]
-
-
 def test_prometheus_jaxprof_block_well_formed():
     text = jaxprof.render_prometheus()
     assert "# TYPE jax_jit_tracings_total counter" in text
@@ -284,6 +276,30 @@ def test_scheduler_counts_dispatches_and_cache_hits():
     assert sum(m["dispatches_total"]["values"].values()) >= 1
     assert m["jobs_finished_total"]["values"]["done"] == 2
     assert sched.jobs[a].phase == sched.jobs[b].phase == "done"
+
+
+def test_http_lock_wait_counts_a_held_lock():
+    """A poll that arrives while the front end's lock is held waits for it,
+    and ``http_lock_wait_seconds_total{route="/v1/poll"}`` counts the wait."""
+    srv = SubStratServer()
+    http = SubStratHTTPServer(srv).start()
+    counter = srv.scheduler.metrics.get("http_lock_wait_seconds_total")
+    replies = []
+    try:
+        poll = threading.Thread(target=lambda: replies.append(
+            SubStratHTTPClient(http.url)._request("/v1/poll?job_id=0")))
+        with http._lock:
+            poll.start()
+            time.sleep(0.5)        # the poll reaches its handler and waits
+            t0 = time.perf_counter()
+            time.sleep(0.3)
+            held_s = time.perf_counter() - t0
+        poll.join(timeout=30)
+        assert not poll.is_alive() and replies
+        assert replies[0][0] == 500    # job 0 was never submitted
+        assert counter.value(route="/v1/poll") >= held_s
+    finally:
+        http.close()
 
 
 # ---------------------------------------------------------------------------
