@@ -75,7 +75,7 @@ from ..automl.engine import (
     SearchState, search_eval_rung, search_init, search_record, search_restore,
     search_result, search_snapshot, search_trial_cohort,
 )
-from ..core.measures import CodedDataset, factorize
+from ..core.measures import CodedDataset, factorize_path
 from ..core.plan import Plan, plan_from_config
 from ..core.strategies import run_strategy, run_strategy_batch
 from ..core.substrat import (
@@ -300,6 +300,11 @@ class Scheduler:
             "cache_hits_total", "DST cache hits at job admission/re-probe")
         self.m_cache_misses = m.counter(
             "cache_misses_total", "cacheable jobs admitted without an entry")
+        self.m_factorize = m.counter(
+            "factorize_total",
+            "tables factorized at admission, by the path that coded them "
+            "(device, or host where float32 cannot hold the table exactly)",
+            ("path",))
         self.m_poisoned = m.counter(
             "poisoned_packs_total",
             "failed packed dispatches re-run solo to isolate blame")
@@ -444,7 +449,8 @@ class Scheduler:
             with self._job_time_span(job, "factorize", "factorize_s",
                                      phase="factorize"):
                 if job.coded is None:
-                    job.coded = factorize(job.X, job.y)
+                    job.coded, path = factorize_path(job.X, job.y)
+                    self.m_factorize.inc(path=path)
                 job.fingerprint = dataset_fingerprint(job.coded)
                 if self.warm_start:
                     # register the dataset's meta-feature vector (free:
