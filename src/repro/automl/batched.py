@@ -62,12 +62,14 @@ buckets.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Callable, Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace
 from ..obs.jaxprof import note_trace
 from .engine import (
     TrialCohort, _apply_preproc, _fit_preproc, _select_features, _trial_key,
@@ -94,9 +96,9 @@ def _variant(ctx, preproc: str, frac: float) -> int:
     cache = ctx["variant_cache"]
     vkey = (preproc, frac)
     if vkey not in cache:
-        X_tr, y_tr, X_val = ctx["X_tr"], ctx["y_tr"], ctx["X_val"]
+        X_tr, X_val = ctx["X_tr"], ctx["X_val"]
         stats = _fit_preproc(preproc, X_tr)
-        fidx = _select_features(frac, X_tr, y_tr)
+        fidx = _select_features(frac, ctx)
         mask = np.zeros((X_tr.shape[1],), np.float32)
         mask[fidx] = 1.0
         cache[vkey] = {
@@ -119,6 +121,28 @@ def _variant_stack(ctx):
             jnp.asarray(np.stack([v["Xval"] for v in vs]), jnp.float32),
         )
     return ctx["variant_stack"]
+
+
+def _variants(ctx, specs) -> Tuple[List[int], tuple]:
+    """Each spec's variant id in ``ctx`` and the ``(V, N, d)`` stacks.
+
+    A call that builds anything (a new variant, hence a new stack) holds
+    the ``automl.variants`` profiler annotation and appends ``(wall t0,
+    wall t1, seconds)`` to ``ctx["variant_builds"]``, which the scheduler
+    records as the job's ``variants`` span.  A search registers its whole
+    population at its first rung, so later rungs build nothing."""
+    cache = ctx["variant_cache"]
+    if "variant_stack" in ctx and all(
+            (s.preproc, s.feature_frac) in cache for s in specs):
+        return ([cache[(s.preproc, s.feature_frac)]["id"] for s in specs],
+                ctx["variant_stack"])
+    t0, w0 = time.perf_counter(), time.time()
+    with trace.annotate("automl.variants"):
+        vids = [_variant(ctx, s.preproc, s.feature_frac) for s in specs]
+        stacks = _variant_stack(ctx)
+    ctx.setdefault("variant_builds", []).append(
+        (w0, time.time(), time.perf_counter() - t0))
+    return vids, stacks
 
 
 def _concat_padded(parts, N_to: int, d_to: int):
@@ -491,13 +515,12 @@ def eval_rung_batched(cohort, tids, rung_i: int, epochs: int, ctx,
     # flop-bound large ones split per width (see WIDTH_PAD_MAX_ROWS)
     pad_widths = ctx["X_tr"].shape[0] <= WIDTH_PAD_MAX_ROWS
 
+    vids, (Xall_tr, Xall_val) = _variants(ctx, cohort)
     trials = [
         _TaggedTrial(0, pos, spec, int(tids[pos]), int(ctx["seed"]),
-                     _variant(ctx, spec.preproc, spec.feature_frac), c,
-                     rung_i, epochs)
+                     vids[pos], c, rung_i, epochs)
         for pos, spec in enumerate(cohort)
     ]
-    Xall_tr, Xall_val = _variant_stack(ctx)
     variants = {v["id"]: v for v in ctx["variant_cache"].values()}
     subbatches = _group_subbatches(trials, pad_widths, variants, epochs)
     budget_active = ctx.get("budget_active", False)
@@ -611,13 +634,14 @@ def _eval_cohorts(cohorts: List[TrialCohort],
     # register every trial's variant in its own job's cache first (caches
     # persist across rungs), then offset local variant ids into one merged
     # stack: merged vid = job's offset + local vid
-    local = []
+    local, stacks = [], []
     for slot, tc in enumerate(cohorts):
         rungs, steps = tc.trial_rungs, tc.trial_steps
+        lvids, stack = _variants(tc.ctx, tc.specs)
+        stacks.append(stack)
         for pos, spec in enumerate(tc.specs):
-            lvid = _variant(tc.ctx, spec.preproc, spec.feature_frac)
             local.append((slot, pos, spec, int(tc.tids[pos]),
-                          int(tc.ctx["seed"]), lvid,
+                          int(tc.ctx["seed"]), lvids[pos],
                           int(rungs[pos]), int(steps[pos])))
     offsets = np.concatenate([[0], np.cumsum(
         [len(tc.ctx["variant_cache"]) for tc in cohorts])])
@@ -627,7 +651,6 @@ def _eval_cohorts(cohorts: List[TrialCohort],
                            rung, nsteps)
               for (slot, pos, spec, tid, seed, lvid, rung, nsteps) in local]
 
-    stacks = [_variant_stack(tc.ctx) for tc in cohorts]
     if hetero:
         # per-job stacks go into the fused program unpadded — the trace
         # zero-pads them to the group-maximal shape (``_concat_padded``);

@@ -110,14 +110,18 @@ def _apply_preproc(name: str, stats, X: np.ndarray) -> np.ndarray:
     return X
 
 
-def _select_features(frac: float, X_train: np.ndarray, y_train: np.ndarray) -> np.ndarray:
-    d = X_train.shape[1]
+def _select_features(frac: float, ctx) -> np.ndarray:
+    """The ``frac`` share of ``ctx["X_tr"]``'s columns of largest variance
+    (cheap, label-free).  The variances are float64, computed once per
+    search: in float32, same-scale columns of nearly equal variance swap
+    places, and with them the order of the model's weight rows."""
+    d = ctx["X_tr"].shape[1]
     k = max(1, int(round(frac * d)))
     if k >= d:
         return np.arange(d)
-    # variance ranking (cheap, label-free)
-    var = X_train.var(axis=0)
-    return np.argsort(-var)[:k]
+    if "feature_var" not in ctx:
+        ctx["feature_var"] = np.asarray(ctx["X_tr"], np.float64).var(axis=0)
+    return np.argsort(-ctx["feature_var"])[:k]
 
 
 def apply_pipeline(spec: PipelineSpec, pre_stats, feat_idx, X: np.ndarray) -> jnp.ndarray:
@@ -189,7 +193,7 @@ def _eval_rung_loop(cohort, tids, rung_i, epochs, ctx, out_of_budget,
         ckey = (spec.preproc, spec.feature_frac)
         if ckey not in ctx["pipe_cache"]:
             stats = _fit_preproc(spec.preproc, ctx["X_tr"])
-            fidx = _select_features(spec.feature_frac, ctx["X_tr"], ctx["y_tr"])
+            fidx = _select_features(spec.feature_frac, ctx)
             Xtr_p = apply_pipeline(spec, stats, fidx, ctx["X_tr"])
             Xval_p = apply_pipeline(spec, stats, fidx, ctx["X_val"])
             ctx["pipe_cache"][ckey] = (stats, fidx, Xtr_p, Xval_p)

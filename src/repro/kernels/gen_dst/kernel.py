@@ -20,10 +20,11 @@ rebuild through ``kernels/entropy``'s histogram kernel and then this one with
 ``applied = 0`` — a zero delta — so *every* generation's fitness comes
 from one code path.
 
-Grid: (P/TP,) over candidate tiles; M and B stay whole inside a block
-(the per-candidate (M, B) histogram is small — Gen-DST datasets have
-dozens of columns and B ≤ 256 bins — so a slab of TP candidates fits
-VMEM comfortably; see §16.2 for the budget arithmetic).
+Grid: (P/TP,) over candidate tiles; M and B stay whole inside a block.
+At B = 256 bins a block of 8 candidates fits Mosaic's 16 MiB of scoped
+VMEM up to about 840 columns: the paper's tables (dozens of columns) use
+a small share of it, AMLB's Fashion-MNIST (785 columns) nearly all (see
+§16.2 for the budget arithmetic).
 """
 from __future__ import annotations
 

@@ -430,6 +430,17 @@ class Scheduler:
             attrs={"seconds": float(seconds), **attrs}))
         job.times[key] = job.times.get(key, 0.0) + float(seconds)
 
+    @staticmethod
+    def _record_variants(job: SubStratJob) -> None:
+        """Record each data-variant build of the dispatch just run
+        (``batched._variants``) as the job's ``variants`` span.  Its seconds
+        are a part of the rung's and go to no ``job.times`` key of their
+        own."""
+        for w0, w1, seconds in job.search.ctx.pop("variant_builds", ()):
+            job.spans.append(trace.make_span(
+                job.trace_id, "variants", w0, w1,
+                attrs={"seconds": float(seconds), "phase": job.phase}))
+
     def _fold_task_spans(self, group: Sequence[SubStratJob],
                          spans: Sequence[dict]) -> None:
         """Copy one remote dispatch's transport/worker spans onto every
@@ -839,6 +850,7 @@ class Scheduler:
         self.m_dispatches.inc(mode=mode)
         self.m_dispatch_latency.observe(wall, mode=mode)
         for job, (scored, positions) in zip(group, outs):
+            self._record_variants(job)
             search_record(job.search, scored, positions, share)
             rung = job.search.rung_i - 1   # search_record advanced past it
             self._record_job_span(job, f"{job.phase}/rung{rung}",
@@ -918,6 +930,7 @@ class Scheduler:
             except Exception as e:   # noqa: BLE001 — isolate job failures
                 self._fail(job, e)
                 continue
+            self._record_variants(job)
             self.solo_rungs += 1
             self.m_dispatches.inc(mode="solo")
             self.m_dispatch_latency.observe(sp["attrs"]["seconds"],

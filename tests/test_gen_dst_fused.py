@@ -61,6 +61,16 @@ def test_fused_kernel_matches_ref(P, M, B, code_max):
     np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref_out))
 
 
+@pytest.mark.parametrize("P,M,B", [(12, 160, 32), (10, 300, 16)])
+def test_fused_kernel_matches_ref_wider_than_a_lane_tile(P, M, B):
+    """M > 128 columns in one block: bit-equal, as at every other width."""
+    args = _case(P, M, B, seed=M + B)
+    c_ref, f_ref_out = fused_delta_fitness_ref(*args)
+    c_k, f_k = fused_delta_fitness_pallas(*args, bins=B, interpret=True)
+    np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_ref))
+    np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref_out))
+
+
 def test_fused_kernel_mass_conservation_and_padding_bins():
     """A row swap conserves per-column mass; codes < B leaves the high
     padding bins untouched (all-zero before and after the delta)."""

@@ -6,7 +6,8 @@ refuses, or a block that does not fit VMEM, fails here on the CPU.  The
 shapes are the paper's D1 table under the default Gen-DST configuration —
 a 360-row subset over the population folded into the column axis
 (100 candidates x 22 or 23 columns, the latter with the target) at
-B = 256 bins — and D8's 122 columns for the fused generation kernel.
+B = 256 bins — and, for the fused generation kernel, D8's 122 columns
+and Fashion-MNIST's 785, whose block of 8 candidates nearly fills VMEM.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and every test worker
@@ -63,7 +64,8 @@ def test_histogram_kernel_compiles_at_gen_dst_population_shape(one_chip, M):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("P,M,B", [(100, 22, 256), (100, 122, 256)])
+@pytest.mark.parametrize("P,M,B", [(100, 22, 256), (100, 122, 256),
+                                   (100, 785, 256)])
 def test_fused_generation_kernel_compiles(one_chip, P, M, B):
     compiled = jax.jit(
         lambda *a: fused_delta_fitness_pallas(*a, bins=B, interpret=False)
