@@ -21,6 +21,11 @@ Search-loop architecture (DESIGN.md §5.5):
     row-crossover generations (``cross_every`` cadence) and route through
     ``kernels/entropy`` under the ``backend=`` switch ("jnp" scatter-add
     reference, or the Pallas histogram kernel).
+  * Sort path (``gen_dst_path`` "sort"): with crossover every generation
+    (``cross_every`` 1, the default) the counts are rebuilt every
+    generation, so on ``backend="jnp"`` none are kept: each candidate
+    column's entropy comes from one sort of its n codes
+    (``measures.sorted_entropy``), with no scatter-add and no (M, B) tensor.
   * Islands: ``num_islands`` independent sub-populations evolved under one
     vmap, with ring elite migration every ``migrate_every`` generations
     (each island's worst ``migrate_frac * phi`` candidates are replaced by
@@ -45,6 +50,7 @@ from .measures import (
     CodedDataset,
     column_entropy_from_counts,
     full_column_entropy,
+    sorted_entropy,
     subset_counts,
     MEASURES,
 )
@@ -53,7 +59,7 @@ from ..kernels.gen_dst.ops import fused_delta_fitness
 from ..obs.jaxprof import note_trace
 
 __all__ = ["GenDSTConfig", "DSTResult", "gen_dst", "gen_dst_batch",
-           "default_dst_size", "random_dst", "GEN_DST_BACKENDS"]
+           "default_dst_size", "random_dst", "gen_dst_path", "GEN_DST_BACKENDS"]
 
 # full-recompute histogram / fused-generation execution backends
 # (DESIGN.md §16.3): "jnp" is the bit-level oracle everywhere.
@@ -102,6 +108,26 @@ class DSTResult(NamedTuple):
     fitness: jax.Array     # scalar, = -|F(d) - F(D)|
     history: jax.Array     # (psi,) best fitness per generation
     f_ref: jax.Array       # F(D)
+    path: str = ""         # gen_dst_path of the search; "" if no GA ran
+
+
+def gen_dst_path(cfg: GenDSTConfig) -> str:
+    """How a search scores its candidates (DESIGN.md §16.3), from the
+    static config the jit specializes on:
+
+    * ``"sort"`` — ``jnp``, entropy, crossover every generation: each
+      candidate column's entropy from one sort of its rows' codes; no counts
+      are carried, since every generation rebuilds them anyway;
+    * ``"counts"`` — ``jnp``, entropy, ``cross_every > 1``: (M, B) counts
+      ride the scan carry for the mutation-only generations' row deltas;
+    * ``"pallas"`` / ``"pallas_fused"`` — the Pallas kernels' counts;
+    * ``"values"`` — a measure other than entropy, on the raw values.
+    """
+    if cfg.measure != "entropy":
+        return "values"
+    if cfg.backend == "jnp":
+        return "sort" if cfg.cross_every == 1 else "counts"
+    return cfg.backend
 
 
 def default_dst_size(N: int, M: int) -> tuple[int, int]:
@@ -181,12 +207,23 @@ def _entropy_fitness(codes, B, f_ref, rows, cols):
     return jax.vmap(one)(rows, cols)
 
 
-def _counts_fitness(counts, cols, f_ref):
-    """Fitness from carried per-candidate counts: (..., M, B) + (..., M)."""
-    h = column_entropy_from_counts(counts)            # (..., M)
+def _entropy_fitness_of(h, cols, f_ref):
+    """Fitness from per-candidate column entropies: (..., M) + (..., M)."""
     cmf = cols.astype(jnp.float32)
     f_d = jnp.sum(h * cmf, axis=-1) / jnp.maximum(cmf.sum(axis=-1), 1.0)
     return -jnp.abs(f_d - f_ref)
+
+
+def _counts_fitness(counts, cols, f_ref):
+    """Fitness from carried per-candidate counts: (..., M, B) + (..., M)."""
+    return _entropy_fitness_of(column_entropy_from_counts(counts), cols, f_ref)
+
+
+def _population_entropy(codes, rows):
+    """(..., n) row sets -> (..., M) column entropies, with no counts: one
+    sort of each candidate column's codes, laid out (..., M, n)."""
+    sub = jnp.take(codes, rows, axis=0)                # (..., n, M)
+    return sorted_entropy(jnp.swapaxes(sub, -1, -2))
 
 
 def _generic_fitness(values, measure_fn, f_ref, rows, cols):
@@ -404,6 +441,8 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
     I, phi = cfg.num_islands, cfg.phi
     entropy = cfg.measure == "entropy"
     use_fused = cfg.backend == "pallas_fused"
+    path = gen_dst_path(cfg)
+    carry_counts = path not in ("sort", "values")
 
     def pop_counts(rows):
         # full-recompute histograms: the fused backend shares the entropy
@@ -420,6 +459,9 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
         f_ref = measure_fn(values)
 
     def fitness_of(rows, cols, counts):
+        if path == "sort":
+            return _entropy_fitness_of(_population_entropy(codes, rows),
+                                       cols, f_ref)
         if entropy:
             return _counts_fitness(counts, cols, f_ref)
         return jax.vmap(
@@ -437,7 +479,8 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
     rows, cols = jax.vmap(
         lambda kk: _init_population(kk, N, M, n, m, phi, target)
     )(jax.random.split(k0, I))                                  # (I, phi, ...)
-    counts0 = pop_counts(rows) if entropy else jnp.zeros((I, phi, 1, 1), jnp.float32)
+    counts0 = (pop_counts(rows) if carry_counts
+               else jnp.zeros((I, phi, 1, 1), jnp.float32))
     fit0 = fitness_of(rows, cols, counts0)
     flat0 = fit0.reshape(-1)
     b0 = jnp.argmax(flat0)
@@ -490,11 +533,11 @@ def _gen_dst_core(key, codes, values, n, m, cfg: GenDSTConfig, B, target):
         else:
             def with_cross(_):
                 rows2, cols2 = jax.vmap(cross1)(xkeys, rows1, cols1)
-                counts2 = pop_counts(rows2) if entropy else counts
+                counts2 = pop_counts(rows2) if carry_counts else counts
                 return rows2, cols2, counts2
 
             def without_cross(_):
-                if not entropy:
+                if not carry_counts:
                     return rows1, cols1, counts
                 if cfg.incremental:
                     counts2 = jax.vmap(
@@ -574,7 +617,7 @@ def gen_dst(
     best_r, best_c, best_f, history, f_ref = _gen_dst_jit(
         key, coded.codes, coded.values, n, m, cfg, coded.max_bins, coded.target_col
     )
-    return DSTResult(best_r, best_c, best_f, history, f_ref)
+    return DSTResult(best_r, best_c, best_f, history, f_ref, gen_dst_path(cfg))
 
 
 def gen_dst_batch(
@@ -613,7 +656,8 @@ def gen_dst_batch(
         jnp.stack([c.values for c in codeds]),
         n, m, cfg, c0.max_bins, c0.target_col,
     )
-    return [DSTResult(rb[i], cb[i], fb[i], hist[i], f_ref[i])
+    path = gen_dst_path(cfg)
+    return [DSTResult(rb[i], cb[i], fb[i], hist[i], f_ref[i], path)
             for i in range(len(codeds))]
 
 

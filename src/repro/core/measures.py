@@ -46,6 +46,7 @@ __all__ = [
     "column_counts",
     "column_entropy_from_counts",
     "column_entropy",
+    "sorted_entropy",
     "dataset_entropy",
     "subset_counts",
     "subset_entropy",
@@ -336,26 +337,46 @@ def dataset_entropy(
     return jnp.sum(h * cm) / jnp.maximum(cm.sum(), 1.0)
 
 
+def sorted_entropy(samples: jax.Array) -> jax.Array:
+    """Shannon entropy (log2) of each row's empirical distribution, from
+    one sort of the row: no histogram and no scatter.
+
+    samples: (..., n) int32 codes, one sample per entry of the last axis.
+    Returns (...,) float32.  Each run of equal codes in the sorted row is
+    one nonempty bin of ``column_counts``: its length, found from the run's
+    start by a running max, is placed at the run's end and zeros elsewhere,
+    and ``column_entropy_from_counts`` reads that length-``n`` vector.  So
+    every term is the histogram path's, computed the same way; only the
+    order of summation differs (DESIGN.md §16.3).
+    """
+    n = samples.shape[-1]
+    s = jnp.sort(samples, axis=-1)
+    i = jnp.arange(n, dtype=jnp.int32)
+    step = s[..., 1:] != s[..., :-1]
+    edge = jnp.ones(s.shape[:-1] + (1,), bool)
+    first = jnp.concatenate([edge, step], axis=-1)
+    last = jnp.concatenate([step, edge], axis=-1)
+    start = jax.lax.cummax(jnp.where(first, i, 0), axis=s.ndim - 1)
+    runs = jnp.where(last, (i - start + 1).astype(jnp.float32), 0.0)
+    return column_entropy_from_counts(runs)
+
+
 @functools.partial(jax.jit, static_argnames=("B", "chunk"))
-def full_column_entropy(codes: jax.Array, B: int, chunk: int = 65536) -> jax.Array:
-    """Column entropy of the full dataset, chunked over rows (bounded memory).
+def full_column_entropy(codes: jax.Array, B: int, chunk: int = 64) -> jax.Array:
+    """Column entropy of the full dataset: one sort per column, at most
+    ``chunk`` columns at a time (bounded memory).
 
     Used once per Gen-DST run to precompute the reference ``F(D)`` terms.
+    ``B`` is the histogram width the other entropy helpers take; the sort
+    needs none.
     """
     note_trace("measures.full_column_entropy")   # body runs only at trace
     N, M = codes.shape
-    pad = (-N) % chunk
-    padded = jnp.pad(codes, ((0, pad), (0, 0)))
-    w = jnp.pad(jnp.ones((N,), jnp.float32), (0, pad))
-    def body(acc, xs):
-        c, wc = xs
-        return acc + column_counts(c, B, wc), None
-    counts, _ = jax.lax.scan(
-        body,
-        jnp.zeros((M, B), jnp.float32),
-        (padded.reshape(-1, chunk, M), w.reshape(-1, chunk)),
-    )
-    return column_entropy_from_counts(counts)
+    steps = -(-M // chunk)
+    width = -(-M // steps)                        # pads fewer than ``steps``
+    cols = jnp.pad(codes.T, ((0, steps * width - M), (0, 0)))  # constant cols
+    h = jax.lax.map(sorted_entropy, cols.reshape(steps, width, N))
+    return h.reshape(-1)[:M]
 
 
 def subset_counts(codes: jax.Array, row_idx: jax.Array, B: int) -> jax.Array:

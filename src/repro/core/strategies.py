@@ -67,6 +67,7 @@ class SubsetResult:
     fitness: float             # -|F(d) - F(D)| (NaN for unscored strategies)
     strategy: str              # registry name (or "<callable>")
     time_s: float              # wall seconds spent producing the subset
+    path: str = ""             # gen_dst_path of a Gen-DST search, else ""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,6 +132,7 @@ def _to_subset_result(dst, strategy: str, time_s: float) -> SubsetResult:
         fitness=float(dst.fitness),
         strategy=strategy,
         time_s=time_s,
+        path=getattr(dst, "path", ""),
     )
 
 

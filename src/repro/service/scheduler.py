@@ -305,6 +305,12 @@ class Scheduler:
             "tables factorized at admission, by the path that coded them "
             "(device, or host where float32 cannot hold the table exactly)",
             ("path",))
+        self.m_gen_dst = m.counter(
+            "gen_dst_total",
+            "Gen-DST searches run, by how they scored their candidates "
+            "(core/gen_dst.gen_dst_path: sort, counts, pallas, pallas_fused, "
+            "values)",
+            ("path",))
         self.m_poisoned = m.counter(
             "poisoned_packs_total",
             "failed packed dispatches re-run solo to isolate blame")
@@ -557,6 +563,8 @@ class Scheduler:
                 and self._try_cache_hit(job))
 
     def _record_subset(self, job: SubStratJob, subset, elapsed: float) -> None:
+        if subset.path:
+            self.m_gen_dst.inc(path=subset.path)
         self._install_subset(job, subset.row_idx, subset.col_mask,
                              subset.fitness)
         if job.cache_key is not None:
